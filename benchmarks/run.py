@@ -41,4 +41,6 @@ def main() -> None:
 
 
 if __name__ == '__main__':
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     main()

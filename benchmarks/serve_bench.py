@@ -735,4 +735,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     main()

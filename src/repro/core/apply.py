@@ -94,28 +94,16 @@ def _sharded_correction(x: jnp.ndarray, d: PackedDelta):
     return ops.delta_correction_sharded(x, d, _MESH, use_pallas=_USE_PALLAS)
 
 
-@jax.custom_vjp
-def _pinned(c: jnp.ndarray) -> jnp.ndarray:
-    """optimization_barrier with an identity gradient.
+def _pinned(c: Any) -> Any:
+    """Pin a fusion boundary: ``optimization_barrier`` over a pytree.
 
-    The barrier pins the correction's fusion boundary (bit-identity
-    across mesh layouts, see apply_linear) but has no differentiation
-    rule — a bare barrier would make every ``deltas=`` forward
-    non-differentiable. The barrier is an identity function, so the
-    straight-through VJP is exact.
+    Used for the correction's boundary (bit-identity across mesh
+    layouts, see apply_linear) and to tie a layer's delta decode to its
+    activations (see _segment_dispatch). The barrier is an identity
+    function and jax differentiates it, so ``deltas=`` forwards stay
+    differentiable.
     """
     return jax.lax.optimization_barrier(c)
-
-
-def _pinned_fwd(c):
-    return _pinned(c), None
-
-
-def _pinned_bwd(_, g):
-    return (g,)
-
-
-_pinned.defvjp(_pinned_fwd, _pinned_bwd)
 
 
 def _replicated(t: jnp.ndarray) -> jnp.ndarray:
@@ -372,7 +360,14 @@ def _segment_dispatch(x: jnp.ndarray, sd: SlotDelta) -> jnp.ndarray:
     the same permutation and the same per-row bits.
     """
     seg = sd.segments
-    d = sd.delta
+    # Tie the packed delta to this layer's activations. Nothing below
+    # reads x before the contraction, so without the tie XLA hoists
+    # every layer's per-row delta gather and decode to the start of the
+    # step and keeps all of them live at once: for an 8-slot decode at
+    # Llama-3.2-1B widths, 9.6 GB of temporaries in a v5e compile
+    # against 2.1 GB with the tie.
+    x, d, values = _pinned((x, sd.delta, sd.values))
+    sd = SlotDelta(d, sd.slots, seg, values, sd.res_map)
     B = x.shape[0]
     lead = x.shape[1:-1]
     tokens_per_row = 1
@@ -473,6 +468,24 @@ def delta_matmul(x: jnp.ndarray, d) -> jnp.ndarray:
     return x @ dense
 
 
+def matmul_rows(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """x [..., h_in] @ w [h_in, h_out]; on the CPU a lone row is computed
+    beside a zero row.
+
+    XLA:CPU gives a one-row float32 product a matrix-vector kernel whose
+    bits differ from those of the same row in a larger product, and a
+    batch-1 decode has to match its row of a slot batch bit for bit.
+    Elsewhere this is ``x @ w``: on a TPU v5e the padded row changed the
+    one-row product's bits and took the batch-1 reference further from
+    the slot batch, not closer.
+    """
+    rows = x.reshape(-1, x.shape[-1])
+    if rows.shape[0] != 1 or jax.default_backend() != "cpu":
+        return x @ w
+    y = (jnp.concatenate([rows, jnp.zeros_like(rows)]) @ w)[:1]
+    return y.reshape(*x.shape[:-1], w.shape[-1])
+
+
 def apply_linear(x: jnp.ndarray, w: jnp.ndarray, d: Optional[PackedDelta] = None) -> jnp.ndarray:
     """Base matmul plus (optionally) the tenant's delta correction.
 
@@ -486,7 +499,7 @@ def apply_linear(x: jnp.ndarray, w: jnp.ndarray, d: Optional[PackedDelta] = None
     bit-identical across mesh layouts (the CI token-identity check).
     """
     x = _replicated(x)
-    y = x @ w
+    y = matmul_rows(x, w)
     if d is not None:
         c = _pinned(delta_matmul(x, d).astype(jnp.float32))
         y = (y.astype(jnp.float32) + c).astype(y.dtype)

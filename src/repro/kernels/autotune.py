@@ -189,25 +189,45 @@ def _sweep_gather_max_t(p, rng) -> tuple:
 
 
 def _sweep_kernel_tiles(p, rng, T: int = 128) -> dict:
-    """Best (tb, ob, kc) for the compiled Pallas kernel (TPU only)."""
+    """Best (tb, ob, kc) for the compiled Pallas kernel (TPU only).
+
+    A packing outside the kernel envelope keeps the default tiles, with
+    the reason printed: ``delta_spmm`` would time the XLA fallback."""
     import jax
     from repro.kernels import ops
-    x = jax.random.normal(rng, (T, p.h_in))
     # only the kernel-tile keys: returning gather_max_t here would
     # clobber the crossover the caller just measured
     best = {k: DEFAULTS[k] for k in ("tb", "ob", "kc")}
+    why = ops.kernel_refusal(p)
+    if why is not None:
+        print(f"# T={T} h_g={p.h_g} h_out={p.h_out}: no tile sweep, the "
+              f"kernels refuse this packing ({why})", flush=True)
+        return best
+    x = jax.random.normal(rng, (T, p.h_in))
     best_us = float("inf")
+    refused = []
     for tb in TB_CANDIDATES:
         for ob in OB_CANDIDATES:
             for kc in KC_CANDIDATES:
                 try:
                     us = _time(lambda x: ops.delta_spmm(
                         x, p, tb=tb, ob=ob, kc=kc, interpret=False), x)
-                except Exception:
+                except Exception as e:  # a candidate the compiler refuses
+                    refused.append(((tb, ob, kc), f"{type(e).__name__}: "
+                                    f"{str(e).splitlines()[0][:200]}"))
                     continue
                 if us < best_us:
                     best_us = us
                     best = {"tb": tb, "ob": ob, "kc": kc}
+    if refused:
+        n = len(TB_CANDIDATES) * len(OB_CANDIDATES) * len(KC_CANDIDATES)
+        print(f"# T={T} h_g={p.h_g} h_out={p.h_out}: the compiler refused "
+              f"{len(refused)}/{n} tile candidates", flush=True)
+        for tiles, why in refused:
+            print(f"#   (tb, ob, kc)={tiles}: {why}", flush=True)
+    if best_us == float("inf"):
+        raise RuntimeError(f"every tile candidate was refused at T={T}, "
+                           f"h_g={p.h_g}, h_out={p.h_out}: {refused[0][1]}")
     return best
 
 

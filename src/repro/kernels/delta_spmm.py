@@ -19,10 +19,11 @@ output tile accumulates in VMEM across groups; the segments kernel adds a
 segment axis next to G (both "arbitrary", consecutive for a fixed output
 block) and scalar-prefetches the tenant-segment layout so BlockSpec index
 maps can route each segment to its tenant's compressed bytes. Supported
-envelope (checked by ops.py, XLA fallback otherwise): h_g <= 256,
-keep <= 128 — the paper's optimal h_g* is 16..256 (Table 4), so the
-envelope covers the method's operating range; row-wise h_g == h_in is the
-fallback's job.
+envelope (checked by ops.py, XLA fallback otherwise): h_g in {128, 256}
+or h_g == h_in <= 256, keep <= 128. The x block is (Tb, h_g), and the
+TPU lowering needs a block's last dim to be a multiple of 128 lanes or
+the whole array, so the paper's smaller h_g* (16..64, Table 4) is the
+fallback's job on the chip, as is row-wise h_g == h_in past 256.
 """
 from __future__ import annotations
 
@@ -34,54 +35,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both
-# so the pinned CI jax (0.4.x) and the latest-jax canary both compile.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 # default kept-values-per-chunk for the in-VMEM scatter loop; bounds the
 # one-hot working set to KC * h_g * Ob * 4B (= 1 MiB at 8 x 256 x 128).
 # Autotune (kernels/autotune.py) can override per envelope point.
 _KC = 8
 
+# the per-tensor quant scalars (scale, zero) live whole in SMEM: a (1, 1)
+# VMEM block over an [R, 1] array breaks the (8, 128) block-tiling rule
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
 
 def _unpack_codes(codes, k_bits: int, keep: int):
     """[Kp, Ob] uint8 -> [keep, Ob] int32 codes (w = physical pack width)."""
+    codes = codes.astype(jnp.int32)
     w = 1 if k_bits <= 1 else 2 if k_bits <= 2 else 4 if k_bits <= 4 else 8
     if w == 8:
-        return codes.astype(jnp.int32)
+        return codes
     per = 8 // w
-    mask = jnp.uint8(2**w - 1)
-    cols = [(codes >> jnp.uint8(i * w)) & mask for i in range(per)]
+    mask = 2**w - 1
+    cols = [(codes >> (i * w)) & mask for i in range(per)]
     q = jnp.stack(cols, axis=1)                      # [Kp, per, Ob]
     q = q.reshape(codes.shape[0] * per, codes.shape[1])
-    return q[:keep].astype(jnp.int32)
+    return q[:keep]
 
 
 def _scatter_dense(idx, vals, h_g: int, keep: int, kc: int = _KC):
     """Build the dense [h_g, Ob] tile from (idx, vals) [keep, Ob] in VMEM.
 
     One-hot-compare scatter, chunked over `keep` (chunk size ``kc``) to
-    bound the working set.
+    bound the working set. The chunk loop is unrolled with static slice
+    bounds: Mosaic has no lowering for a dynamic slice of a loaded value.
     """
     Ob = idx.shape[-1]
-    iota_h = jax.lax.broadcasted_iota(jnp.int32, (1, h_g, 1), 1)
-    n_chunks = (keep + kc - 1) // kc
-    pad = n_chunks * kc - keep
-    if pad:
-        idx = jnp.pad(idx, ((0, pad), (0, 0)))
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
-    idx = idx.reshape(n_chunks, kc, Ob)
-    vals = vals.reshape(n_chunks, kc, Ob)
-
-    def body(c, dense):
-        sel_i = idx[c][:, None, :]                   # [KC, 1, Ob]
-        sel_v = vals[c][:, None, :]
-        oh = (sel_i == iota_h).astype(jnp.float32)   # [KC, h_g, Ob]
-        return dense + jnp.sum(oh * sel_v, axis=0)
-
-    dense0 = jnp.zeros((h_g, Ob), jnp.float32)
-    return jax.lax.fori_loop(0, n_chunks, body, dense0)
+    iota_h = jax.lax.broadcasted_iota(jnp.int32, (1, h_g, Ob), 1)
+    dense = jnp.zeros((h_g, Ob), jnp.float32)
+    for c0 in range(0, keep, kc):
+        sel_i = idx[c0:c0 + kc][:, None, :]          # [<=KC, 1, Ob]
+        sel_v = vals[c0:c0 + kc][:, None, :]
+        dense = dense + jnp.sum(jnp.where(sel_i == iota_h, sel_v, 0.0),
+                                axis=0)
+    return dense
 
 
 def _decode_arrays(idx, codes, scale, zero, *, k_bits, keep, h_g, kc=_KC):
@@ -141,12 +134,12 @@ def delta_spmm_kernel(x, idx, codes, scale, zero, *, h_g: int, keep: int,
             pl.BlockSpec((tb, h_g), lambda t, o, g: (t, g)),
             pl.BlockSpec((1, keep, ob), lambda t, o, g: (g, 0, o)),
             pl.BlockSpec((1, Kp, ob), lambda t, o, g: (g, 0, o)),
-            pl.BlockSpec((1, 1), lambda t, o, g: (0, 0)),
-            pl.BlockSpec((1, 1), lambda t, o, g: (0, 0)),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=pl.BlockSpec((tb, ob), lambda t, o, g: (t, o)),
         out_shape=jax.ShapeDtypeStruct((T, h_out), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, idx, codes, scale, zero)
@@ -194,12 +187,12 @@ def fused_base_delta_kernel(x, w, idx, codes, scale, zero, *, h_g: int, keep: in
             pl.BlockSpec((h_g, ob), lambda t, o, g: (g, o)),
             pl.BlockSpec((1, keep, ob), lambda t, o, g: (g, 0, o)),
             pl.BlockSpec((1, Kp, ob), lambda t, o, g: (g, 0, o)),
-            pl.BlockSpec((1, 1), lambda t, o, g: (0, 0)),
-            pl.BlockSpec((1, 1), lambda t, o, g: (0, 0)),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=pl.BlockSpec((tb, ob), lambda t, o, g: (t, o)),
         out_shape=jax.ShapeDtypeStruct((T, h_out), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, idx, codes, scale, zero)
@@ -227,8 +220,9 @@ def _segments_body(seg_rows_ref, seg_offs_ref, x_ref, idx_ref, codes_ref,
     # once per batch row
     @pl.when((end > start) & (start < row0 + tb) & (end > row0))
     def _():
+        r = seg_rows_ref[s]
         dense = _decode_arrays(idx_ref[0, 0], codes_ref[0, 0],
-                               scale_ref[0, 0], zero_ref[0, 0],
+                               scale_ref[r, 0], zero_ref[r, 0],
                                k_bits=k_bits, keep=keep, h_g=h_g, kc=kc)
         x = x_ref[...].astype(jnp.float32)            # [tb, h_g]
         y = jnp.dot(x, dense, preferred_element_type=jnp.float32)
@@ -282,8 +276,8 @@ def delta_spmm_segments_kernel(x, idx, codes, scale, zero, seg_rows,
                          lambda t, o, s, g, sr, so: (sr[s], g, 0, o)),
             pl.BlockSpec((1, 1, Kp, ob),
                          lambda t, o, s, g, sr, so: (sr[s], g, 0, o)),
-            pl.BlockSpec((1, 1), lambda t, o, s, g, sr, so: (sr[s], 0)),
-            pl.BlockSpec((1, 1), lambda t, o, s, g, sr, so: (sr[s], 0)),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=pl.BlockSpec((tb, ob), lambda t, o, s, g, sr, so: (t, o)),
     )
@@ -292,7 +286,7 @@ def delta_spmm_segments_kernel(x, idx, codes, scale, zero, seg_rows,
                           tb=tb, kc=kc),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((T, h_out), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -325,12 +319,12 @@ def dequant_kernel(idx, codes, scale, zero, *, h_g: int, keep: int,
         in_specs=[
             pl.BlockSpec((1, keep, ob), lambda g, o: (g, 0, o)),
             pl.BlockSpec((1, Kp, ob), lambda g, o: (g, 0, o)),
-            pl.BlockSpec((1, 1), lambda g, o: (0, 0)),
-            pl.BlockSpec((1, 1), lambda g, o: (0, 0)),
+            _SMEM,
+            _SMEM,
         ],
         out_specs=pl.BlockSpec((h_g, ob), lambda g, o: (g, o)),
         out_shape=jax.ShapeDtypeStruct((G * h_g, h_out), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "parallel")),
         interpret=interpret,
     )(idx, codes, scale, zero)

@@ -45,6 +45,7 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.pack import PackedDelta, decode_values, reconstruct_dense
@@ -66,18 +67,30 @@ def _flat_gather_idx(d: PackedDelta, idx: jnp.ndarray) -> jnp.ndarray:
 
 
 def dense_correction(x2: jnp.ndarray, d: PackedDelta) -> jnp.ndarray:
-    """x2 [T, h_in] @ dense(delta) -> [T, h_out] f32 (reconstruct path)."""
-    return x2.astype(jnp.float32) @ reconstruct_dense(d)
+    """x2 [T, h_in] @ dense(delta) -> [T, h_out] f32 (reconstruct path).
+
+    Computed transposed, with the output columns as the product's rows:
+    XLA:CPU gives a row of a matmul the same bits for any row count, but
+    not a column for any column count. So the mesh path's per-shard
+    column slice bit-matches the replicated product. The barrier keeps
+    the simplifier from folding the transposes back into ``x @ dense``.
+    """
+    dense_t, x_t = jax.lax.optimization_barrier(
+        (reconstruct_dense(d).T, x2.astype(jnp.float32).T))
+    return (dense_t @ x_t).T
 
 
 def gather_correction(x2: jnp.ndarray, d: PackedDelta) -> jnp.ndarray:
     """x2 [T, h_in] -> [T, h_out] f32 without materializing the dense delta."""
     vals = decode_values(d)                          # [G, K, O] f32
     G, K, O = vals.shape
-    gidx = _flat_gather_idx(d, d.idx).reshape(-1)    # [G*K*O]
-    sel = x2.astype(jnp.float32)[:, gidx].reshape(x2.shape[0], G * K, O)
-    # multiply + axis-sum (not einsum): batch-extent-stable bits, see above
-    return (sel * vals.reshape(G * K, O)[None]).sum(axis=1)
+    T = x2.shape[0]
+    gidx = _flat_gather_idx(d, d.idx).reshape(1, G * K * O)
+    # the per-row paths' contraction, every row on this one delta: one
+    # gather + reduce shape for shared and per-row deltas, one set of bits
+    return _rows_core(x2, jnp.broadcast_to(gidx, (T, G * K * O)),
+                      jnp.broadcast_to(vals.reshape(1, G * K, O),
+                                       (T, G * K, O)))
 
 
 def correction(x2: jnp.ndarray, d: PackedDelta, *,

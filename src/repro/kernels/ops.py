@@ -8,11 +8,9 @@ reconstruct-then-matmul at prefill counts) is used — mathematically
 identical. Tile sizes (tb, ob, kc) default to the persisted autotune
 table (``kernels.autotune``); explicit arguments always win.
 
-Output columns that don't divide the tile run on the largest reasonable
-divisor tile (no padding); only when every divisor is pathologically
-small (prime-ish ``h_out``) is the packed column axis padded up to a
-pow2 tile and the result sliced — at most one partial tile instead of
-degrading to an ``ob=1`` grid.
+Output columns that don't divide the tile run on the largest lane-legal
+divisor tile (no padding); only when ``h_out`` has none is the packed
+column axis padded up to a 128-lane multiple and the result sliced.
 
 Multi-device: :func:`delta_correction_sharded` partitions the packed
 delta along its output-column axis over the mesh ``model`` axis with
@@ -27,15 +25,20 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.pack import PackedDelta, reconstruct_dense
 from repro.kernels import autotune, fallback
 from repro.kernels import delta_spmm as _k
 
-# CPU containers run kernels in interpret mode; real TPUs compile them.
-_INTERPRET = jax.default_backend() != "tpu"
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    """Interpret mode unless the caller pinned it: kernels compile on a
+    TPU and run in the Pallas interpreter elsewhere. Asked at call time,
+    so importing this module never initialises a backend."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def _note(site: str, **attrs) -> None:
@@ -46,11 +49,38 @@ def _note(site: str, **attrs) -> None:
 
 MAX_HG = 256
 MAX_KEEP = 128
+# TPU lane width: a block's last dim must be a multiple of it or span the
+# whole array, and the kernels' x block is (Tb, h_g)
+LANES = 128
+
+
+def kernel_refusal(d: PackedDelta) -> Optional[str]:
+    """Why the Pallas kernels cannot take ``d`` (None when they can)."""
+    if d.stack_shape():
+        return f"stacked delta {d.stack_shape()}"
+    if d.h_g > MAX_HG:
+        return f"h_g={d.h_g} > {MAX_HG}"
+    if d.h_g % LANES and d.h_g != d.h_in:
+        return (f"h_g={d.h_g} is neither a multiple of {LANES} nor "
+                f"h_in={d.h_in}")
+    if d.keep > MAX_KEEP:
+        return f"keep={d.keep} > {MAX_KEEP}"
+    if d.k_bits is not None and not 1 <= d.k_bits <= 8:
+        return f"k_bits={d.k_bits} not in 1..8"
+    return None
 
 
 def kernel_supported(d: PackedDelta) -> bool:
-    return (not d.stack_shape()) and d.h_g <= MAX_HG and d.keep <= MAX_KEEP \
-        and (d.k_bits is None or 1 <= d.k_bits <= 8)
+    return kernel_refusal(d) is None
+
+
+def _refused(site: str, d: PackedDelta) -> bool:
+    """True (and the reason noted for attribution) when ``d`` is outside
+    the kernel envelope and ``site`` must take the XLA fallback."""
+    why = kernel_refusal(d)
+    if why is not None:
+        _note(site, kernel_refused=why)
+    return why is not None
 
 
 def _scalars(d: PackedDelta):
@@ -81,26 +111,21 @@ def _tiles(d: PackedDelta, tb, ob, kc, t: Optional[int] = None) -> dict:
             "gather_max_t": tuned["gather_max_t"]}
 
 
-# smallest column tile worth running unpadded; below this a divisor tile
-# makes a pathological grid and pad-to-pow2 wins
-_MIN_COL_TILE = 32
-
-
 def _col_tile(h_out: int, ob: int) -> int:
     """Effective column tile for ``h_out`` output columns.
 
     Prefer a divisor of ``h_out`` (no padding, no wasted columns — in
-    the fused kernel padding also copies the whole base matrix); only
-    when the best divisor is pathologically small (< _MIN_COL_TILE, e.g.
-    prime-ish h_out) fall back to a pow2 tile and let the caller pad
-    and slice."""
+    the fused kernel padding also copies the whole base matrix). A tile
+    must be lane-legal on a TPU: a multiple of :data:`LANES` or all of
+    ``h_out``. Only when no legal divisor exists fall back to a
+    lane-multiple tile and let the caller pad and slice."""
     cap = min(ob, h_out)
-    if h_out % cap == 0:
+    if cap == h_out:
         return cap
-    for t in range(cap, _MIN_COL_TILE - 1, -1):
+    for t in range(cap - cap % LANES, 0, -LANES):
         if h_out % t == 0:
             return t
-    return min(ob, _pow2_ceil(h_out))
+    return -(-cap // LANES) * LANES
 
 
 def _pad_cols(d: PackedDelta, ob: int) -> PackedDelta:
@@ -123,10 +148,9 @@ def delta_spmm(x: jnp.ndarray, d: PackedDelta, *, tb: Optional[int] = None,
                ob: Optional[int] = None, kc: Optional[int] = None,
                interpret: Optional[bool] = None) -> jnp.ndarray:
     """y = x @ dequant(d). x [..., h_in] -> [..., h_out] (f32)."""
-    if interpret is None:
-        interpret = _INTERPRET
+    interpret = _interpret(interpret)
     t = _tiles(d, tb, ob, kc, t=x.size // x.shape[-1])
-    if not kernel_supported(d):
+    if _refused("delta_spmm", d):
         return fallback.correction_nd(x, d,
                                       gather_max_t=t["gather_max_t"])
     lead = x.shape[:-1]
@@ -159,15 +183,14 @@ def delta_spmm_slots(x: jnp.ndarray, d: PackedDelta, *,
     used — it never materializes a dense ``[B, h_in, h_out]`` tensor, so
     rows sharing a tenant no longer multiply a dense reconstruction.
     """
-    if interpret is None:
-        interpret = _INTERPRET
+    interpret = _interpret(interpret)
     B = x.shape[0]
     if d.stack_shape() != (B,):
         raise ValueError(
             f"stacked delta stack_shape={d.stack_shape()} must equal "
             f"({B},) — one delta row per slot row of x {x.shape}")
     probe = d.index(0)
-    if interpret or not kernel_supported(probe):
+    if interpret or _refused("delta_spmm_slots", probe):
         _note("delta_spmm_slots", formulation="per-row-gather",
               codec=d.codec, B=int(B))
         return fallback.gather_correction_rows(x, d)
@@ -207,14 +230,13 @@ def delta_spmm_segments(x_sorted: jnp.ndarray, d: PackedDelta,
     values-consuming kernel variant is not worth a second TPU code
     path. Packed-only (values=None) stays the always-correct fallback.
     """
-    if interpret is None:
-        interpret = _INTERPRET
+    interpret = _interpret(interpret)
     if values is not None:
         return fallback.segment_correction(x_sorted, d, seg_rows, seg_offsets,
                                            values=values, res_map=res_map)
     probe = d.index(0)
     t = _tiles(probe, tb, ob, kc, t=x_sorted.shape[0])
-    if not kernel_supported(probe):
+    if _refused("delta_spmm_segments", probe):
         return fallback.segment_correction(x_sorted, d, seg_rows, seg_offsets)
     T = x_sorted.shape[0]
     if T <= t["tb"]:
@@ -342,24 +364,22 @@ def delta_correction_sharded(x: jnp.ndarray, d: PackedDelta, mesh, *,
             n_data = mesh.shape.get("data", 1)
             if seg_rows.shape[0] != n_data or x.shape[0] % n_data:
                 return None
-            fn = shard_map(body_seg, mesh=mesh,
-                           in_specs=(P(*(["data"] + [None] * (x.ndim - 1))),
-                                     last_model(d.idx.ndim),
-                                     last_model(d.codes.ndim),
-                                     repl(scale.ndim), repl(zero.ndim),
-                                     P("data", None), P("data", None),
-                                     *val_specs),
-                           out_specs=P(*(["data"] + [None] * (x.ndim - 2)
-                                         + ["model"])),
-                           check_rep=False)
+            fn = jax.shard_map(
+                body_seg, mesh=mesh,
+                in_specs=(P(*(["data"] + [None] * (x.ndim - 1))),
+                          last_model(d.idx.ndim), last_model(d.codes.ndim),
+                          repl(scale.ndim), repl(zero.ndim),
+                          P("data", None), P("data", None), *val_specs),
+                out_specs=P(*(["data"] + [None] * (x.ndim - 2) + ["model"])),
+                check_vma=False)
         else:
-            fn = shard_map(body_seg, mesh=mesh,
-                           in_specs=(repl(x.ndim), last_model(d.idx.ndim),
-                                     last_model(d.codes.ndim),
-                                     repl(scale.ndim), repl(zero.ndim),
-                                     repl(1), repl(1), *val_specs),
-                           out_specs=last_model(x.ndim),
-                           check_rep=False)
+            fn = jax.shard_map(
+                body_seg, mesh=mesh,
+                in_specs=(repl(x.ndim), last_model(d.idx.ndim),
+                          last_model(d.codes.ndim), repl(scale.ndim),
+                          repl(zero.ndim), repl(1), repl(1), *val_specs),
+                out_specs=last_model(x.ndim),
+                check_vma=False)
         return fn(x, d.idx, d.codes, scale, zero, seg_rows, seg_offsets,
                   *val_args)
     gather_max_t = t_glob["gather_max_t"]
@@ -378,12 +398,12 @@ def delta_correction_sharded(x: jnp.ndarray, d: PackedDelta, mesh, *,
         # same dtype round-trip as the replicated path (bit-identity)
         return y.astype(xb.dtype)
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(repl(x.ndim), last_model(d.idx.ndim),
-                             last_model(d.codes.ndim), repl(scale.ndim),
-                             repl(zero.ndim)),
-                   out_specs=last_model(x.ndim),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(repl(x.ndim), last_model(d.idx.ndim),
+                                 last_model(d.codes.ndim), repl(scale.ndim),
+                                 repl(zero.ndim)),
+                       out_specs=last_model(x.ndim),
+                       check_vma=False)
     return fn(x, d.idx, d.codes, scale, zero)
 
 
@@ -392,9 +412,8 @@ def fused_base_delta(x: jnp.ndarray, w: jnp.ndarray, d: PackedDelta, *,
                      kc: Optional[int] = None,
                      interpret: Optional[bool] = None) -> jnp.ndarray:
     """y = x @ (w + dequant(d)); reads x once (separate computation, fused)."""
-    if interpret is None:
-        interpret = _INTERPRET
-    if not kernel_supported(d):
+    interpret = _interpret(interpret)
+    if _refused("fused_base_delta", d):
         return (x @ w) + delta_spmm(x, d, interpret=interpret).astype(w.dtype)
     t = _tiles(d, tb, ob, kc, t=x.size // x.shape[-1])
     lead = x.shape[:-1]
@@ -417,9 +436,8 @@ def dequant(d: PackedDelta, *, ob: Optional[int] = None,
             kc: Optional[int] = None,
             interpret: Optional[bool] = None) -> jnp.ndarray:
     """Materialize dense delta [h_in, h_out] (merge path)."""
-    if interpret is None:
-        interpret = _INTERPRET
-    if not kernel_supported(d):
+    interpret = _interpret(interpret)
+    if _refused("dequant", d):
         return reconstruct_dense(d)
     t = _tiles(d, None, ob, kc)
     ob_eff = _col_tile(d.h_out, t["ob"])
@@ -468,12 +486,5 @@ def per_row_decode_tiles(batch: int, *, n_groups: int, h_out: int,
 def _pow2_floor(n: int) -> int:
     p = 1
     while p * 2 <= n:
-        p *= 2
-    return p
-
-
-def _pow2_ceil(n: int) -> int:
-    p = 1
-    while p < n:
         p *= 2
     return p

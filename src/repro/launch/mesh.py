@@ -31,16 +31,27 @@ from __future__ import annotations
 from typing import Any, Optional
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.pack import PackedDelta
 from repro.dist import sharding as shd
 
 
+def make_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with ``Auto`` axis types.
+
+    These layouts place arrays with ``NamedSharding`` and sharding
+    constraints and let the partitioner propagate the rest. The default
+    ``Explicit`` axes of ``jax.make_mesh`` type every intermediate by its
+    sharding instead, and then refuse reshapes such as the head split in
+    ``qkv_project`` (``ShardingTypeError``)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -50,7 +61,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
         raise ValueError(
             f"mesh (data={data}, model={model}) needs {data * model} "
             f"devices but only {n} are visible")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def make_serving_mesh(devices: Optional[int] = None, *, data: int = 1):
@@ -73,7 +84,7 @@ def make_serving_mesh(devices: Optional[int] = None, *, data: int = 1):
     if n % data:
         raise ValueError(f"data={data} must divide the device count {n} "
                          "(equal contiguous shard pools)")
-    return jax.make_mesh((data, n // data), ("data", "model"))
+    return make_mesh((data, n // data), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------

@@ -27,7 +27,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.core import BitDeltaSpec, DeltaDQSpec, compress
 from repro.models import lm
 from repro.serve import ContinuousEngine
-from repro.utils import tree_bytes
+from repro.utils import enable_compile_cache, tree_bytes
 
 RATIO_SPECS = {
     8: DeltaDQSpec(alpha=8.0, k_bits=None, h_g=16),
@@ -307,6 +307,7 @@ def main():
                     help="snapshot file for --telemetry-snapshot-secs "
                          "(atomically replaced on each write)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     if args.data > 1 and args.devices % args.data:

@@ -21,10 +21,12 @@ from repro.configs import get_config, get_smoke_config
 from repro.data import PretrainMixture
 from repro.dist import ShardingRules, tree_shardings, zero1_shardings
 from repro.dist.sharding import TRAIN_OVERRIDES
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.optim import adamw, schedule
 from repro.optim.adamw import AdamWConfig
 from repro.train import make_train_step
+from repro.utils import enable_compile_cache
 
 
 def main():
@@ -45,9 +47,10 @@ def main():
                     help="int8 error-feedback compressed DP all-reduce")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
-    mesh = jax.make_mesh((args.data, args.model), ("data", "model"))
+    mesh = make_mesh((args.data, args.model), ("data", "model"))
     rules = ShardingRules(mesh).with_overrides(**TRAIN_OVERRIDES)
 
     p_specs, p_axes = lm.param_specs(cfg), lm.param_axes(cfg)

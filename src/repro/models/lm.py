@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.arch import ArchConfig
-from repro.core.apply import apply_linear, dget, dindex
+from repro.core.apply import apply_linear, dget, dindex, matmul_rows
 from repro.models import moe as moe_mod
 from repro.models import rglru as rec_mod
 from repro.models import ssm as ssm_mod
@@ -653,7 +653,7 @@ def embed_tokens(cfg, params, tokens):
 def unembed(cfg, params, h, deltas=None):
     h = rmsnorm(h, params["final_norm"]["scale"], cfg.norm_eps)
     if cfg.tie_embeddings:
-        logits = h @ params["embed"]["tok"].T
+        logits = matmul_rows(h, params["embed"]["tok"].T)
     else:
         logits = apply_linear(h, params["unembed"]["w"], dget(dget(deltas, "unembed"), "w"))
     return softcap(logits.astype(jnp.float32), cfg.logit_softcap)

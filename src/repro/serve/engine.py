@@ -1558,6 +1558,10 @@ class Engine:
 
         tenant=None serves the raw base model (control arm).
         """
+        # this engine is unsharded: clear the process-global apply-mode
+        # mesh a mesh engine in this process may have left installed
+        from repro.core.apply import set_mesh
+        set_mesh(None)
         deltas = self.store.get(tenant).deltas if tenant else None
         B, S = prompts.shape
         enc_len = 0

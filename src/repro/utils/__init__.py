@@ -1,3 +1,4 @@
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.pytree import (
     flatten_with_paths,
     map_with_paths,
@@ -7,6 +8,7 @@ from repro.utils.pytree import (
 )
 
 __all__ = [
+    "enable_compile_cache",
     "flatten_with_paths",
     "map_with_paths",
     "path_str",

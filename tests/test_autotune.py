@@ -111,7 +111,8 @@ def test_ops_respects_tuned_tiles(tmp_path, monkeypatch):
     numerically correct (padding handles non-divisible tiles)."""
     rng = jax.random.PRNGKey(0)
     delta = jax.random.normal(rng, (128, 192)) * 0.01
-    p = groupwise_dropout_pack(rng, delta, h_g=64, alpha=8, k_bits=4)
+    p = groupwise_dropout_pack(rng, delta, h_g=128, alpha=8, k_bits=4)
+    assert ops.kernel_supported(p)
     path = tmp_path / "table.json"
     key = autotune.envelope_key(p.h_g, p.keep, p.k_bits, p.h_in, p.h_out)
     path.write_text(json.dumps(
@@ -135,14 +136,34 @@ def test_ops_respects_tuned_tiles(tmp_path, monkeypatch):
 def test_col_tile_prefers_divisors():
     """Benign non-divisible h_out runs unpadded on a divisor tile (the
     fused kernel would otherwise copy-pad the whole base matrix); only
-    prime-ish h_out falls back to pad-to-pow2."""
+    h_out with no lane-legal divisor falls back to pad-and-slice."""
     from repro.kernels.ops import _col_tile
     assert _col_tile(256, 128) == 128     # divides: use the tuned tile
     assert _col_tile(96, 128) == 96       # divisor tile, no padding
     assert _col_tile(40, 64) == 40
-    assert _col_tile(192, 128) == 96      # largest divisor <= cap
+    assert _col_tile(1024, 384) == 256    # largest lane-multiple divisor
+    # a 96-lane divisor is not lane-legal on a TPU: pad to 128-lane tiles
+    assert _col_tile(192, 128) == 128
     assert 251 % _col_tile(251, 128) != 0  # prime: pad-and-slice path
     assert _col_tile(251, 128) >= 32
+
+
+def test_tile_sweep_skips_refused_packing(monkeypatch, capsys):
+    """A packing outside the kernel envelope keeps the default tiles and
+    says why, without timing anything (delta_spmm would run the XLA
+    fallback for every candidate)."""
+    rng = jax.random.PRNGKey(0)
+    p = groupwise_dropout_pack(rng, jax.random.normal(rng, (64, 128)) * 0.01,
+                               h_g=16, alpha=8, k_bits=4)
+    assert ops.kernel_refusal(p) is not None
+
+    def timed(*a, **k):
+        raise AssertionError("a refused packing was timed")
+
+    monkeypatch.setattr(autotune, "_time", timed)
+    got = autotune._sweep_kernel_tiles(p, rng, T=8)
+    assert got == {k: autotune.DEFAULTS[k] for k in ("tb", "ob", "kc")}
+    assert "the kernels refuse this packing (h_g=16" in capsys.readouterr().out
 
 
 def test_decode_tile_accounting():

@@ -81,13 +81,14 @@ def test_elastic_remesh_restore(subproc):
     from repro.optim.adamw import AdamWConfig
     from repro.train import make_train_step
     from repro.dist import ShardingRules, tree_shardings
+    from repro.launch.mesh import make_mesh
 
     cfg = get_smoke_config('llama3.2-1b')
     data = PretrainMixture(vocab=cfg.vocab, seq_len=16, batch=4)
     step_fn = make_train_step(cfg, AdamWConfig(lr=1e-3))
 
     def run(mesh_shape, restore_dir=None, start=0, n=3, save_dir=None):
-        mesh = jax.make_mesh(mesh_shape, ('data', 'model'))
+        mesh = make_mesh(mesh_shape, ('data', 'model'))
         rules = ShardingRules(mesh)
         p_specs, p_axes = lm.param_specs(cfg), lm.param_axes(cfg)
         p_sh = tree_shardings(rules, p_specs, p_axes)

@@ -59,7 +59,6 @@ def test_compressed_allreduce_matches_mean(subproc):
 
     # per-device distinct values, replicated container: emulate by shard_map
     # over a [4, n] array where row i is device i's local gradient
-    from jax.experimental.shard_map import shard_map
     rng = np.random.default_rng(0)
     local = rng.normal(size=(4, 1000)).astype(np.float32)
     want = local.mean(0)
@@ -68,8 +67,8 @@ def test_compressed_allreduce_matches_mean(subproc):
         from repro.dist.grad_compress import _compressed_psum_flat
         return _compressed_psum_flat(v[0], 'data', 4)[None]
 
-    got = shard_map(per_device, mesh=mesh, in_specs=P('data'), out_specs=P('data'),
-                    check_rep=False)(jnp.asarray(local))
+    got = jax.shard_map(per_device, mesh=mesh, in_specs=P('data'),
+                        out_specs=P('data'), check_vma=False)(jnp.asarray(local))
     got = np.asarray(got)
     # every device row holds the same reduced result
     for i in range(4):

@@ -144,7 +144,8 @@ def test_segment_fallback_parity(rows):
 
 @pytest.mark.parametrize("h_out", [256, 96, 251])
 def test_segment_kernel_interpret_parity(h_out):
-    stk, _ = _stacked(3, h_out=h_out)
+    stk, _ = _stacked(3, h_out=h_out, h_g=128)
+    assert ops.kernel_supported(stk.index(0))
     rows = [2, 0, 2, 1, 0, 2, 1, 0]
     B = len(rows)
     x = jax.random.normal(jax.random.PRNGKey(6), (B, 128))
@@ -160,7 +161,8 @@ def test_segment_kernel_interpret_parity(h_out):
 
 def test_segment_kernel_multi_row_blocks():
     """T spanning several row tiles: segment/tile overlap logic."""
-    stk, _ = _stacked(2, h_in=64, h_out=128, h_g=32, alpha=4)
+    stk, _ = _stacked(2, h_in=64, h_out=128, h_g=64, alpha=4)
+    assert ops.kernel_supported(stk.index(0))
     rows = [0] * 5 + [1] * 11          # 16 rows, tb forced to 8
     B = len(rows)
     x = jax.random.normal(jax.random.PRNGKey(7), (B, 64))
@@ -272,8 +274,10 @@ def test_kernel_envelope_sweep(h_g, keep, k_bits):
     """delta_spmm / fused / segments (interpret) vs the dense oracle
     across the whole supported envelope."""
     alpha = h_g // keep
-    h_in, h_out = h_g * 2, 128
+    # inside the kernel envelope: h_g lane-aligned, or all of h_in
+    h_in, h_out = (h_g if h_g % ops.LANES else 2 * h_g), 128
     p = _pack(h_in, h_out, h_g, alpha, k_bits, seed=h_g + keep)
+    assert ops.kernel_supported(p), ops.kernel_refusal(p)
     x = jax.random.normal(jax.random.PRNGKey(9), (16, h_in))
     dense = reconstruct_dense(p)
     want = np.asarray(x @ dense)

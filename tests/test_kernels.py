@@ -21,14 +21,15 @@ except ImportError:                                   # pragma: no cover
     HAVE_HYPOTHESIS = False
 
 SWEEP = [
-    # (T, h_in, h_out, h_g, alpha, k_bits)
-    (64, 256, 128, 64, 8, 4),
+    # (T, h_in, h_out, h_g, alpha, k_bits); h_g inside the kernel
+    # envelope: a multiple of 128 lanes, or all of h_in
+    (64, 256, 128, 128, 8, 4),
     (32, 512, 256, 128, 4, 8),
-    (128, 256, 384, 32, 2, 2),
-    (16, 128, 128, 16, 8, 1),
-    (8, 64, 96, 16, 4, None),
+    (128, 256, 384, 128, 2, 2),
+    (16, 128, 128, 128, 8, 1),
+    (8, 64, 96, 64, 4, None),
     (100, 256, 96, 256, 16, 4),     # padding path (T not multiple of tile)
-    (1, 128, 64, 32, 4, 4),         # decode shape (T=1)
+    (1, 128, 64, 128, 4, 4),        # decode shape (T=1)
 ]
 
 
@@ -41,6 +42,7 @@ def _pack(h_in, h_out, h_g, alpha, k, seed=0, scale=0.01):
 @pytest.mark.parametrize("T,h_in,h_out,h_g,alpha,k", SWEEP)
 def test_delta_spmm_vs_ref(T, h_in, h_out, h_g, alpha, k):
     p = _pack(h_in, h_out, h_g, alpha, k)
+    assert ops.kernel_supported(p), ops.kernel_refusal(p)
     x = jax.random.normal(jax.random.PRNGKey(1), (T, h_in))
     np.testing.assert_allclose(np.asarray(ops.delta_spmm(x, p, interpret=True)),
                                np.asarray(ref.delta_spmm_ref(x, p)),
@@ -67,7 +69,7 @@ def test_dequant_vs_ref(T, h_in, h_out, h_g, alpha, k):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_dtype_sweep(dtype):
-    p = _pack(256, 128, 64, 8, 4)
+    p = _pack(256, 128, 128, 8, 4)
     x = jax.random.normal(jax.random.PRNGKey(3), (32, 256)).astype(dtype)
     got = ops.delta_spmm(x, p, interpret=True)
     want = ref.delta_spmm_ref(x.astype(jnp.float32), p)
@@ -76,12 +78,33 @@ def test_dtype_sweep(dtype):
                                rtol=0.05 if dtype == jnp.bfloat16 else 1e-4)
 
 
-def test_fallback_outside_envelope():
-    # h_g > MAX_HG routes to the XLA fallback and still matches the oracle
-    p = _pack(1024, 32, 1024, 8, 4)
+def test_import_leaves_the_backend_alone(subproc):
+    """Interpret mode is decided per call: importing the kernels (or the
+    serving stack above them) must not initialise a JAX backend, which
+    on a TPU host would claim the chip."""
+    out = subproc("""
+    from jax._src import xla_bridge
+    import repro.kernels.ops, repro.serve, repro.launch.serve  # noqa: F401
+    print(xla_bridge.backends_are_initialized())
+    """, n_devices=1)
+    assert out.strip() == "False"
+
+
+@pytest.mark.parametrize("h_in,h_g", [
+    (1024, 1024),     # h_g > MAX_HG
+    (128, 64),        # not lane-aligned: the x block (tb, 64) cannot lower
+])
+def test_fallback_outside_envelope(h_in, h_g):
+    # routes to the XLA fallback, says why, and still matches the oracle
+    from repro.serve.trace import attribution
+    p = _pack(h_in, 32, h_g, 8, 4)
     assert not ops.kernel_supported(p)
-    x = jax.random.normal(jax.random.PRNGKey(4), (8, 1024))
-    np.testing.assert_allclose(np.asarray(ops.delta_spmm(x, p, interpret=True)),
+    x = jax.random.normal(jax.random.PRNGKey(4), (8, h_in))
+    with attribution() as notes:
+        got = ops.delta_spmm(x, p, interpret=True)
+    assert {"site": "delta_spmm",
+            "kernel_refused": ops.kernel_refusal(p)} in notes
+    np.testing.assert_allclose(np.asarray(got),
                                np.asarray(ref.delta_spmm_ref(x, p)),
                                atol=1e-4, rtol=1e-4)
 

@@ -498,7 +498,9 @@ if HAVE_HYPOTHESIS:
             rows = set(eng._rows.values())
             assert len(rows) == len(eng._rows)          # rows unique
             assert 0 not in rows                        # row 0 is base
-            assert not rows & set(eng._table._free)     # live != free
+            # live != free (the table exists from the first registration)
+            free = set(eng._table._free) if eng._table is not None else set()
+            assert not rows & free
         eng.run()
         for r in pending:
             assert r.done

@@ -52,9 +52,9 @@ def test_microbatch_equivalence(setup):
 
 @pytest.mark.slow  # full grad trace through every delta site (~27s)
 def test_loss_differentiable_through_delta_path(setup):
-    """grad through deltas= must work: the fusion-pinning barrier in
-    apply_linear carries a straight-through VJP (regression: a bare
-    optimization_barrier has no differentiation rule)."""
+    """grad through deltas= must work through the fusion-pinning barriers
+    in apply_linear and the slot dispatch (optimization_barrier is an
+    identity with a differentiation rule)."""
     from repro.core import DeltaDQSpec, compress
     cfg, params, data = setup
     ft = jax.tree.map(lambda p: p * 1.01 if p.ndim >= 2 else p, params)
