@@ -38,6 +38,15 @@ _USE_PALLAS = False
 # path: gather a per-row delta stack and reconstruct/apply per row.
 _SLOT_DISPATCH = "segments"
 
+# The named scope every delta correction runs under (apply_linear,
+# apply_linear_batched). It lands in each correction op's HLO ``op_name``
+# metadata, whatever the formulation (gather, dense, segments, Pallas,
+# sharded), so a profile attributes device time to the correction by
+# scope. The optimization barrier at the same site keeps correction ops
+# out of the base matmul's fusions. Metadata only: bits, fusion and the
+# number of compiles are unchanged.
+CORRECTION_SCOPE = "delta_correction"
+
 # Active serving mesh (set by mesh-mode engines/launchers). When a mesh
 # with a >1 `model` axis is installed, every delta correction routes
 # through the shard_map'd output-column-partitioned path in
@@ -501,7 +510,8 @@ def apply_linear(x: jnp.ndarray, w: jnp.ndarray, d: Optional[PackedDelta] = None
     x = _replicated(x)
     y = matmul_rows(x, w)
     if d is not None:
-        c = _pinned(delta_matmul(x, d).astype(jnp.float32))
+        with jax.named_scope(CORRECTION_SCOPE):
+            c = _pinned(delta_matmul(x, d).astype(jnp.float32))
         y = (y.astype(jnp.float32) + c).astype(y.dtype)
     return _replicated(y)
 
@@ -522,13 +532,14 @@ def apply_linear_batched(x: jnp.ndarray, w: jnp.ndarray,
     # never mixed-batch through expert buffers — see the raise above)
     y = jnp.einsum("e...d,edf->e...f", x, w)
     if d is not None:
-        dense = reconstruct_dense(d, dtype=x.dtype)  # [E, h_in, h_out]
-        # same fusion pin + fixed-precision add as apply_linear, so MoE
-        # expert-site corrections keep the mesh bit-identity contract too
-        # deltalint: allow[DL001] audited MoE correction: grouped-per-tenant
-        # serving only, so batch extent is fixed per tenant group
-        c = _pinned(jnp.einsum("e...d,edf->e...f", x, dense)
-                    .astype(jnp.float32))
+        with jax.named_scope(CORRECTION_SCOPE):
+            dense = reconstruct_dense(d, dtype=x.dtype)  # [E, h_in, h_out]
+            # same fusion pin + fixed-precision add as apply_linear, so MoE
+            # expert-site corrections keep the mesh bit-identity contract
+            # deltalint: allow[DL001] audited MoE correction: grouped-per-
+            # tenant serving only, so batch extent is fixed per tenant group
+            c = _pinned(jnp.einsum("e...d,edf->e...f", x, dense)
+                        .astype(jnp.float32))
         y = (y.astype(jnp.float32) + c).astype(y.dtype)
     return _replicated(y)
 

@@ -48,7 +48,7 @@ from repro.core.pack import PackedDelta, decode_values
 from repro.models import lm
 from repro.serve.kv import SlotKVCache
 from repro.serve.metrics import Metrics
-from repro.serve.trace import EventBus, attribution, path_label
+from repro.serve.trace import EventBus, attribution, path_label, phase
 from repro.serve.scheduler import (
     ChunkBudget,
     ChunkQueue,
@@ -547,7 +547,9 @@ class ContinuousEngine:
     ``self.bus`` and all consumers — including ``Metrics`` itself —
     read that same stream. Timestamps come exclusively from the
     injectable clock, so traces are deterministic under
-    ``VirtualClock``.
+    ``VirtualClock``. Each step also opens profiler phase spans
+    (``engine.step`` and its parts, ``serve.trace.phase``) on the
+    profiler's clock; their host seconds add up in ``metrics.phases``.
     """
 
     def __init__(self, cfg: ArchConfig, base_params: Any, *,
@@ -1118,35 +1120,51 @@ class ContinuousEngine:
         set_mesh(self.mesh)
         set_slot_dispatch(self.slot_dispatch)
 
+    def _phase(self, name: str, **attrs):
+        """A profiler span (``serve.trace.phase``) whose host seconds add
+        to ``self.metrics.phases``."""
+        return phase(name, self.metrics.phases, **attrs)
+
     def _prefill_into(self, slot: int, req: Request, now: float) -> None:
-        self._install_mesh()
-        self._refresh_stacked()
         L = req.prompt_len
         bucket = self.buckets.bucket(L)
-        pad = bucket - L
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, pad:] = req.prompt
-        positions = (np.arange(bucket, dtype=np.int32) - pad)[None]
-        if req.tenant is not None:
-            deltas = self.store.get(req.tenant).deltas
-        else:
-            deltas = self._zero_tree    # None when no tenants registered
-        row_cache = lm.init_cache(self.cfg, 1, self.max_seq)
-        self.prefill_shapes.add(bucket)
-        sig = ("prefill", bucket)
-        with attribution() as notes:
-            logits, row_cache = self._prefill(
-                self.base, {"tokens": jnp.asarray(tokens),
-                            "positions": jnp.asarray(positions)},
-                row_cache, deltas)
-        if notes:   # dispatch sites only report while jax traces
-            self.bus.emit("jit_trace", now, signature=sig, site="prefill",
-                          first=sig not in self._path_notes,
-                          notes=list(notes))
-            self._path_notes[sig] = list(notes)
-        self.kv.insert(slot, row_cache)
+        with self._phase("engine.prefill", rid=req.rid, tenant=req.tenant,
+                         prompt_len=L, bucket=bucket):
+            with self._phase("engine.prefill.prep"):
+                self._install_mesh()
+                self._refresh_stacked()
+                pad = bucket - L
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, pad:] = req.prompt
+                positions = (np.arange(bucket, dtype=np.int32) - pad)[None]
+                if req.tenant is not None:
+                    deltas = self.store.get(req.tenant).deltas
+                else:
+                    deltas = self._zero_tree  # None when no tenants registered
+                row_cache = lm.init_cache(self.cfg, 1, self.max_seq)
+                batch = {"tokens": jnp.asarray(tokens),
+                         "positions": jnp.asarray(positions)}
+            self.prefill_shapes.add(bucket)
+            sig = ("prefill", bucket)
+            with self._phase("engine.prefill.dispatch"):
+                with attribution() as notes:
+                    logits, row_cache = self._prefill(self.base, batch,
+                                                      row_cache, deltas)
+                if notes:   # dispatch sites only report while jax traces
+                    self.bus.emit("jit_trace", now, signature=sig,
+                                  site="prefill",
+                                  first=sig not in self._path_notes,
+                                  notes=list(notes))
+                    self._path_notes[sig] = list(notes)
+            with self._phase("engine.prefill.insert"):
+                self.kv.insert(slot, row_cache)
+            with self._phase("engine.prefill.wait"):
+                first = int(np.asarray(jnp.argmax(logits, axis=-1))[0])
+            with self._phase("engine.prefill.emit"):
+                self._prefill_emit(slot, req, now, first, L, bucket)
 
-        first = int(np.asarray(jnp.argmax(logits, axis=-1))[0])
+    def _prefill_emit(self, slot: int, req: Request, now: float, first: int,
+                      L: int, bucket: int) -> None:
         t_first = self._now()
         slack = None if req.deadline is None else req.deadline - now
         self.bus.emit("admit", now, rid=req.rid, tenant=req.tenant, slot=slot,
@@ -1224,113 +1242,112 @@ class ContinuousEngine:
             task = self._chunks.next_task()
         if task is None and not decode_slots:
             return False
-        self._install_mesh()
-        self._refresh_stacked()
-        act = np.zeros(self.n_slots, bool)
-        act[decode_slots] = True
-        # parked slots (free, or mid-prefill) are masked to tenant row 0
-        # so their tenants are not dequantized and don't inflate the
-        # unique-tenant segment count
-        rows_eff = np.where(act, self._row, 0)
-        sd, res_used = self._slot_delta(rows_eff)
-        if task is None:
-            sig = ("decode_masked", len(self._groups), bool(res_used))
-            with attribution() as notes:
-                nxt, new_cache = self._decode_masked(
-                    self.base, self.kv.cache,
-                    jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos),
-                    jnp.asarray(act), sd)
-            cn = None
-            site = "decode_masked"
-        else:
-            req = task.request
-            C = self.chunk_size if self._chunk_pad else task.length
-            ctok = np.zeros((1, C), np.int32)
-            ctok[0, :task.length] = req.prompt[task.start:
-                                               task.start + task.length]
-            # pad positions run past every real query position, so the
-            # padded keys are causally masked; their K/V ring writes are
-            # dropped by the model's valid mask
-            cpos = (task.start + np.arange(C, dtype=np.int32))[None]
-            cvalid = np.zeros((1, C), bool)
-            cvalid[0, :task.length] = True
-            cd = self._chunk_delta(int(self._row[task.slot]))
-            sig = ("combined", C, len(self._groups), bool(res_used))
-            with attribution() as notes:
-                nxt, cn, new_cache = self._combined(
-                    self.base, self.kv.cache,
-                    jnp.asarray(self._tok[:, None]), jnp.asarray(self._pos),
-                    jnp.asarray(act), sd, jnp.asarray(ctok),
-                    jnp.asarray(cpos), jnp.asarray(cvalid),
-                    jnp.int32(task.slot), cd)
-            site = "combined"
-        if notes:   # non-empty notes == this call (re)traced under jit
-            self.bus.emit("jit_trace", now, signature=sig, site=site,
-                          first=sig not in self._path_notes,
-                          notes=list(notes))
-            self._path_notes[sig] = list(notes)
-        path_notes = self._path_notes.get(sig, [])
-        self.kv.update(new_cache)
-        nxt = np.asarray(nxt)
-        t = self._now()
-        self.bus.emit(
-            "step", t, t_start=now, n_active=len(decode_slots),
-            chunk_tokens=task.length if task is not None else 0,
-            shard_active=self.sched.shard_occupancy() if self.data > 1
-            else None,
-            shard_unique=self.sched.shard_unique_tenants(rows_eff),
-            residency_used=res_used,
-            path="base" if sd is None else path_label(path_notes),
-            notes=path_notes, recompiled=bool(notes))
-        for slot in decode_slots:
-            state = self.sched.slots[slot]
-            req = state.request
-            tok = int(nxt[slot])
-            self._tok[slot] = tok
-            self._pos[slot] += 1
-            state.next_token = tok
-            state.pos = int(self._pos[slot])
-            fin = req.emit(tok)
+        chunk = {} if task is None else {"chunk_rid": task.request.rid}
+        with self._phase("engine.decode", n_active=len(decode_slots),
+                         groups=len(self._groups), **chunk):
+            with self._phase("engine.decode.prep"):
+                self._install_mesh()
+                self._refresh_stacked()
+                act = np.zeros(self.n_slots, bool)
+                act[decode_slots] = True
+                # parked slots (free, or mid-prefill) are masked to tenant
+                # row 0 so their tenants are not dequantized and don't
+                # inflate the unique-tenant segment count
+                rows_eff = np.where(act, self._row, 0)
+                sd, res_used = self._slot_delta(rows_eff)
+                args = (jnp.asarray(self._tok[:, None]),
+                        jnp.asarray(self._pos), jnp.asarray(act), sd)
+                if task is not None:
+                    req = task.request
+                    C = self.chunk_size if self._chunk_pad else task.length
+                    ctok = np.zeros((1, C), np.int32)
+                    ctok[0, :task.length] = req.prompt[task.start:
+                                                       task.start + task.length]
+                    # pad positions run past every real query position, so
+                    # the padded keys are causally masked; their K/V ring
+                    # writes are dropped by the model's valid mask
+                    cpos = (task.start + np.arange(C, dtype=np.int32))[None]
+                    cvalid = np.zeros((1, C), bool)
+                    cvalid[0, :task.length] = True
+                    cd = self._chunk_delta(int(self._row[task.slot]))
+                    args += (jnp.asarray(ctok), jnp.asarray(cpos),
+                             jnp.asarray(cvalid), jnp.int32(task.slot), cd)
+            if task is None:
+                sig = ("decode_masked", len(self._groups), bool(res_used))
+                site = "decode_masked"
+            else:
+                sig = ("combined", C, len(self._groups), bool(res_used))
+                site = "combined"
+            with self._phase("engine.decode.dispatch"):
+                with attribution() as notes:
+                    if task is None:
+                        nxt, new_cache = self._decode_masked(
+                            self.base, self.kv.cache, *args)
+                        cn = None
+                    else:
+                        nxt, cn, new_cache = self._combined(
+                            self.base, self.kv.cache, *args)
+                if notes:   # non-empty notes == this call (re)traced
+                    self.bus.emit("jit_trace", now, signature=sig, site=site,
+                                  first=sig not in self._path_notes,
+                                  notes=list(notes))
+                    self._path_notes[sig] = list(notes)
+                self.kv.update(new_cache)
+            with self._phase("engine.decode.wait"):
+                nxt = np.asarray(nxt)
+            with self._phase("engine.decode.emit"):
+                path_notes = self._path_notes.get(sig, [])
+                t = self._now()
+                self.bus.emit(
+                    "step", t, t_start=now, n_active=len(decode_slots),
+                    chunk_tokens=task.length if task is not None else 0,
+                    shard_active=self.sched.shard_occupancy()
+                    if self.data > 1 else None,
+                    shard_unique=self.sched.shard_unique_tenants(rows_eff),
+                    residency_used=res_used,
+                    path="base" if sd is None else path_label(path_notes),
+                    notes=path_notes, recompiled=bool(notes))
+                self._emit_tokens(decode_slots, nxt, t)
+                if task is not None:
+                    self._advance_chunk(task, now, t, cn, len(decode_slots))
+        return True
+
+    def _advance_chunk(self, task, now: float, t: float, cn,
+                       n_decode: int) -> None:
+        """Book a prompt chunk the step at ``t`` prefilled; after the last
+        chunk its first token (from ``cn``) is emitted."""
+        req = task.request
+        self._chunks.advance(task)
+        state = self.sched.slots[task.slot]
+        state.pos = task.start + task.length
+        self.bus.emit("prefill_chunk", t, rid=req.rid, tenant=req.tenant,
+                      slot=task.slot, t_start=now, start=task.start,
+                      length=task.length, last=task.last,
+                      n_decode=n_decode)
+        if task.last:
+            # the final chunk's last real position predicts the first
+            # generated token — exactly what whole-prompt prefill's
+            # h[:, -1:] unembed returns
+            first = int(np.asarray(cn)[0, task.length - 1])
+            L = req.prompt_len
+            self.bus.emit("prefill", t, rid=req.rid, tenant=req.tenant,
+                          t_start=self._chunk_t0.pop(req.rid, now),
+                          prompt_len=L, bucket=None, slot=task.slot)
+            self.bus.emit("first_token", t, rid=req.rid,
+                          tenant=req.tenant, ttft=t - req.arrival)
             self.bus.emit("token", t, rid=req.rid, tenant=req.tenant)
             if self.data > 1:
                 self.bus.emit("shard_token", t,
-                              shard=self.sched.shard_of(slot))
+                              shard=self.sched.shard_of(task.slot))
+            req.t_first_token = t
+            self._tok[task.slot] = first
+            self._pos[task.slot] = L
+            state.prefilling = False
+            state.next_token = first
+            state.pos = L
+            fin = req.emit(first)
             if fin:
-                self._finish(slot, t)
-        if task is not None:
-            req = task.request
-            self._chunks.advance(task)
-            state = self.sched.slots[task.slot]
-            state.pos = task.start + task.length
-            self.bus.emit("prefill_chunk", t, rid=req.rid, tenant=req.tenant,
-                          slot=task.slot, t_start=now, start=task.start,
-                          length=task.length, last=task.last,
-                          n_decode=len(decode_slots))
-            if task.last:
-                # the final chunk's last real position predicts the first
-                # generated token — exactly what whole-prompt prefill's
-                # h[:, -1:] unembed returns
-                first = int(np.asarray(cn)[0, task.length - 1])
-                L = req.prompt_len
-                self.bus.emit("prefill", t, rid=req.rid, tenant=req.tenant,
-                              t_start=self._chunk_t0.pop(req.rid, now),
-                              prompt_len=L, bucket=None, slot=task.slot)
-                self.bus.emit("first_token", t, rid=req.rid,
-                              tenant=req.tenant, ttft=t - req.arrival)
-                self.bus.emit("token", t, rid=req.rid, tenant=req.tenant)
-                if self.data > 1:
-                    self.bus.emit("shard_token", t,
-                                  shard=self.sched.shard_of(task.slot))
-                req.t_first_token = t
-                self._tok[task.slot] = first
-                self._pos[task.slot] = L
-                state.prefilling = False
-                state.next_token = first
-                state.pos = L
-                fin = req.emit(first)
-                if fin:
-                    self._finish(task.slot, t)
-        return True
+                self._finish(task.slot, t)
 
     def _slot_delta(self, rows: np.ndarray):
         """Per-slot delta dispatch tree for one decode step.
@@ -1413,32 +1430,46 @@ class ContinuousEngine:
         active = self.sched.active_slots()
         if not active:
             return
-        self._install_mesh()
-        self._refresh_stacked()
-        sd, res_used = self._slot_delta(self._row)
-        sig = ("decode", len(self._groups), bool(res_used))
-        with attribution() as notes:
-            nxt, new_cache = self._decode(
-                self.base, self.kv.cache, jnp.asarray(self._tok[:, None]),
-                jnp.asarray(self._pos), sd)
-        if notes:   # non-empty notes == this call (re)traced under jit
-            self.bus.emit("jit_trace", now, signature=sig, site="decode",
-                          first=sig not in self._path_notes,
-                          notes=list(notes))
-            self._path_notes[sig] = list(notes)
-        path_notes = self._path_notes.get(sig, [])
-        self.kv.update(new_cache)
-        nxt = np.asarray(nxt)
-        t = self._now()
-        self.bus.emit(
-            "step", t, t_start=now, n_active=len(active),
-            shard_active=self.sched.shard_occupancy() if self.data > 1
-            else None,
-            shard_unique=self.sched.shard_unique_tenants(self._row),
-            residency_used=res_used,
-            path="base" if sd is None else path_label(path_notes),
-            notes=path_notes, recompiled=bool(notes))
-        for slot in active:
+        with self._phase("engine.decode", n_active=len(active),
+                         groups=len(self._groups)):
+            with self._phase("engine.decode.prep"):
+                self._install_mesh()
+                self._refresh_stacked()
+                sd, res_used = self._slot_delta(self._row)
+                tok = jnp.asarray(self._tok[:, None])
+                pos = jnp.asarray(self._pos)
+            sig = ("decode", len(self._groups), bool(res_used))
+            with self._phase("engine.decode.dispatch"):
+                with attribution() as notes:
+                    nxt, new_cache = self._decode(self.base, self.kv.cache,
+                                                  tok, pos, sd)
+                if notes:   # non-empty notes == this call (re)traced
+                    self.bus.emit("jit_trace", now, signature=sig,
+                                  site="decode",
+                                  first=sig not in self._path_notes,
+                                  notes=list(notes))
+                    self._path_notes[sig] = list(notes)
+                self.kv.update(new_cache)
+            with self._phase("engine.decode.wait"):
+                nxt = np.asarray(nxt)
+            with self._phase("engine.decode.emit"):
+                path_notes = self._path_notes.get(sig, [])
+                t = self._now()
+                self.bus.emit(
+                    "step", t, t_start=now, n_active=len(active),
+                    shard_active=self.sched.shard_occupancy()
+                    if self.data > 1 else None,
+                    shard_unique=self.sched.shard_unique_tenants(self._row),
+                    residency_used=res_used,
+                    path="base" if sd is None else path_label(path_notes),
+                    notes=path_notes, recompiled=bool(notes))
+                self._emit_tokens(active, nxt, t)
+
+    def _emit_tokens(self, slots: List[int], nxt: np.ndarray,
+                     t: float) -> None:
+        """Hand each decoded slot its token from ``nxt``; finish those
+        that are done."""
+        for slot in slots:
             state = self.sched.slots[slot]
             req = state.request
             tok = int(nxt[slot])
@@ -1456,19 +1487,22 @@ class ContinuousEngine:
 
     def step(self, now: float) -> bool:
         """One scheduler iteration: admit into free slots, then decode."""
-        worked = False
-        for slot, req in self.sched.admit(self.queue, now):
-            self.kv.claim(slot)      # kv free list mirrors the slot table
+        with self._phase("engine.step", n_active=self.sched.n_active,
+                         queue=len(self.queue)):
+            with self._phase("engine.admit"):
+                admitted = self.sched.admit(self.queue, now)
+            for slot, req in admitted:
+                self.kv.claim(slot)  # kv free list mirrors the slot table
+                if self.chunked:
+                    self._admit_chunked(slot, req, now)
+                else:
+                    self._prefill_into(slot, req, now)
+            worked = bool(admitted)
             if self.chunked:
-                self._admit_chunked(slot, req, now)
-            else:
-                self._prefill_into(slot, req, now)
-            worked = True
-        if self.chunked:
-            worked = self._combined_step(now) or worked
-        elif self.sched.n_active:
-            self._decode_all(now)
-            worked = True
+                worked = self._combined_step(now) or worked
+            elif self.sched.n_active:
+                self._decode_all(now)
+                worked = True
         return worked
 
     def run(self, max_steps: int = 1_000_000) -> Metrics:

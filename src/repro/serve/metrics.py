@@ -25,6 +25,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.serve.telemetry import StreamingHistogram
+from repro.serve.trace import PhaseTimes
 
 
 @dataclass
@@ -95,6 +96,10 @@ class Metrics:
         # shows up as an ITL spike on every in-flight request.
         self.itls = StreamingHistogram()
         self._last_token_t: Dict[int, float] = {}   # rid -> last token time
+        # host seconds per engine phase span (serve.trace.phase), on the
+        # wall clock: the one field the bus does not feed, so report()
+        # leaves it out and stays deterministic under VirtualClock
+        self.phases = PhaseTimes()
         self.t_start: Optional[float] = None
         self.t_end: Optional[float] = None
 

@@ -19,9 +19,6 @@ Exports:
   violations per tenant, fed from the engine's event stream
   (``serve.trace.EventBus``); the deadline comes from the scheduler's
   existing per-request ``deadline`` field.
-* :func:`prometheus_text`    — Prometheus-style text exposition of a
-  :class:`~repro.serve.metrics.Metrics` collector (+ optional SLO
-  counters).
 * :class:`TelemetrySnapshotWriter` — periodic JSON snapshot file driven
   by engine time, for scraping a live serve process.
 """
@@ -150,19 +147,6 @@ class StreamingHistogram:
                 counts[self.bucket_index(v)] += 1
         return counts
 
-    def cumulative(self) -> List[tuple]:
-        """[(le_bound, cumulative_count)] over non-trivial buckets plus the
-        +Inf terminal — the Prometheus histogram exposition shape."""
-        counts = self.bucket_counts()
-        out = []
-        cum = 0
-        for i, c in enumerate(counts):
-            cum += int(c)
-            if c and i <= self.n_buckets:
-                out.append((self.bucket_le(i), cum))
-        out.append((math.inf, self.n))
-        return out
-
     def merge(self, other: "StreamingHistogram") -> "StreamingHistogram":
         """Pooled histogram (e.g. all-tenant TTFT). Same layout required."""
         if (self.lo, self.per_decade, self.n_buckets) != \
@@ -272,69 +256,6 @@ class SLOCounters:
             "ttft_violations": dict(sorted(self.ttft_violations.items())),
             "itl_violations": dict(sorted(self.itl_violations.items())),
         }
-
-
-# ---------------------------------------------------------------------------
-# Prometheus-style text exposition
-# ---------------------------------------------------------------------------
-def _fmt_le(le: float) -> str:
-    return "+Inf" if math.isinf(le) else f"{le:.6g}"
-
-
-def prometheus_text(metrics, slo: Optional[SLOCounters] = None,
-                    namespace: str = "repro_serve") -> str:
-    """Render a ``Metrics`` collector as Prometheus text exposition.
-
-    Counters for requests/tokens/steps (per tenant and per decode path),
-    histograms (cumulative log buckets + _sum/_count) for TTFT, queue
-    wait and latency. Pure function of the collector — safe to call any
-    time, including from the snapshot writer.
-    """
-    lines: List[str] = []
-
-    def counter(name: str, value, labels: str = "", help_: str = ""):
-        if help_:
-            lines.append(f"# HELP {namespace}_{name} {help_}")
-        lines.append(f"# TYPE {namespace}_{name} counter")
-        lines.append(f"{namespace}_{name}{labels} {value}")
-
-    lines.append(f"# TYPE {namespace}_requests_total counter")
-    lines.append(f"# TYPE {namespace}_tokens_total counter")
-    for tenant, st in sorted(metrics.tenants.items()):
-        lab = f'{{tenant="{tenant}"}}'
-        lines.append(f"{namespace}_requests_total{lab} {st.n_requests}")
-        lines.append(f"{namespace}_tokens_total{lab} {st.n_tokens}")
-    counter("decode_steps_total", metrics.n_decode_steps)
-    counter("prefills_total", metrics.n_prefills)
-    if getattr(metrics, "decode_paths", None):
-        lines.append(f"# TYPE {namespace}_decode_path_steps_total counter")
-        for path, n in sorted(metrics.decode_paths.items()):
-            lines.append(f"{namespace}_decode_path_steps_total"
-                         f'{{path="{path}"}} {n}')
-
-    for hist_name, attr in (("ttft_seconds", "ttfts"),
-                            ("queue_wait_seconds", "queue_waits"),
-                            ("latency_seconds", "latencies")):
-        lines.append(f"# TYPE {namespace}_{hist_name} histogram")
-        for tenant, st in sorted(metrics.tenants.items()):
-            h: StreamingHistogram = getattr(st, attr)
-            for le, cum in h.cumulative():
-                lines.append(
-                    f'{namespace}_{hist_name}_bucket{{tenant="{tenant}",'
-                    f'le="{_fmt_le(le)}"}} {cum}')
-            lines.append(f'{namespace}_{hist_name}_sum{{tenant="{tenant}"}} '
-                         f"{h.total:.9g}")
-            lines.append(f'{namespace}_{hist_name}_count'
-                         f'{{tenant="{tenant}"}} {h.n}')
-
-    if slo is not None:
-        for name, d in (("deadline_misses_total", slo.deadline_misses),
-                        ("ttft_violations_total", slo.ttft_violations),
-                        ("itl_violations_total", slo.itl_violations)):
-            lines.append(f"# TYPE {namespace}_{name} counter")
-            for tenant, n in sorted(d.items()):
-                lines.append(f'{namespace}_{name}{{tenant="{tenant}"}} {n}')
-    return "\n".join(lines) + "\n"
 
 
 # ---------------------------------------------------------------------------

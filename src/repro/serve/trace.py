@@ -1,6 +1,6 @@
 """Request-lifecycle tracing and decode-path attribution for the engine.
 
-Three pieces:
+Four pieces:
 
 * **Event bus** — the engine emits typed :class:`ServeEvent`\\ s at every
   hook site (submit → admit → prefill → first-token → token → done, plus
@@ -27,9 +27,24 @@ Three pieces:
   chunk scheduling is visible per request); pid 2 = the engine
   (decode_step spans with path-attribution args, jit_trace instants).
 
+* **Phase spans** — :func:`phase` is the profiler-clock companion to
+  the bus: a thin ``jax.profiler.TraceAnnotation`` the engine opens
+  around each part of a step (``engine.step`` > ``engine.admit``,
+  ``engine.prefill`` and ``engine.decode`` > ``.prep`` / ``.dispatch``
+  / ``.insert`` / ``.wait`` / ``.emit``). The bus keeps the request
+  lifecycle on the engine clock, deterministic under ``VirtualClock``;
+  phase spans carry host intervals on the clock the profiler's device
+  planes use, so a device idle gap can be put down to the host work
+  beside it. Where a phase span and a bus event mark the same thing
+  they share a name (``admit``, ``prefill``). With no profiler session
+  a span records nothing (about a microsecond). :class:`PhaseTimes`
+  also sums each phase's host seconds, so the host time of a step is
+  known without a profile.
+
 The tracer holds **no clock**: every timestamp comes from events, which
 carry the engine's injectable clock — traces are deterministic under
-``VirtualClock`` and this module performs zero wall-clock reads.
+``VirtualClock``. The one wall-clock read in this module is the host
+duration of a phase span, which never reaches the bus.
 
 ``python -m repro.serve.trace --validate trace.json`` checks an emitted
 file (JSON parses, ≥1 request span with child prefill+decode spans,
@@ -39,13 +54,17 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "EVENT_SCHEMA",
     "ServeEvent", "EventBus", "Tracer",
+    "PhaseTimes", "phase",
     "attribution", "note_path", "path_label",
     "validate_chrome_trace",
 ]
@@ -107,6 +126,55 @@ class EventBus:
         ev = ServeEvent(kind, t, attrs)
         for c in self.consumers:
             c.consume(ev)
+
+
+# ---------------------------------------------------------------------------
+# Phase spans on the profiler clock
+# ---------------------------------------------------------------------------
+class PhaseTimes:
+    """Host seconds per phase name, summed over the spans that closed,
+    with their count (``Metrics.phases``; reset with the metrics)."""
+
+    def __init__(self):
+        self.seconds: Dict[str, float] = {}
+        self.count: Dict[str, int] = {}
+
+    def add(self, name: str, seconds: float) -> None:
+        self.seconds[name] = self.seconds.get(name, 0.0) + seconds
+        self.count[name] = self.count.get(name, 0) + 1
+
+    def host_ms(self, span: str) -> Optional[float]:
+        """Mean milliseconds of ``span`` less its ``<span>.wait`` child,
+        the time the host blocked on the device; None before any span."""
+        n = self.count.get(span)
+        if not n:
+            return None
+        busy = self.seconds[span] - self.seconds.get(span + ".wait", 0.0)
+        return busy / n * 1e3
+
+
+class _Phase:
+    __slots__ = ("_ann", "_name", "_times", "_t0")
+
+    def __init__(self, name: str, times: PhaseTimes, attrs: dict):
+        self._ann = TraceAnnotation(name, **attrs)
+        self._name = name
+        self._times = times
+
+    def __enter__(self) -> "_Phase":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._times.add(self._name, time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
+
+
+def phase(name: str, times: PhaseTimes, **attrs) -> _Phase:
+    """A host span ``name`` on the profiler's clock, with ``attrs`` as
+    its arguments; its host seconds add to ``times``."""
+    return _Phase(name, times, attrs)
 
 
 # ---------------------------------------------------------------------------

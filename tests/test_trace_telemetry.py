@@ -26,7 +26,6 @@ from repro.serve.telemetry import (
     SLOCounters,
     StreamingHistogram,
     TelemetrySnapshotWriter,
-    prometheus_text,
 )
 from repro.serve.trace import (
     EventBus,
@@ -93,18 +92,6 @@ def test_histogram_bucket_layout_roundtrip():
         i = h.bucket_index(x)
         assert h.bucket_le(i - 1) <= x <= h.bucket_le(i) * (1 + 1e-12)
     assert h.bucket_index(1e12) == h.n_buckets + 1    # overflow
-
-
-def test_histogram_cumulative_is_prometheus_shaped():
-    h = StreamingHistogram(exact_cap=4)
-    for x in (0.001, 0.002, 0.004, 0.3, 0.3, 9.0):
-        h.record(x)
-    cum = h.cumulative()
-    les = [le for le, _ in cum]
-    counts = [c for _, c in cum]
-    assert les == sorted(les)                         # le bounds ascend
-    assert counts == sorted(counts)                   # cumulative ascends
-    assert math.isinf(les[-1]) and counts[-1] == h.n  # +Inf terminal = count
 
 
 def test_histogram_merge_exact_and_bucketed():
@@ -204,32 +191,6 @@ def _hist_with(*xs):
     for x in xs:
         h.record(x)
     return h
-
-
-# ---------------------------------------------------------------------------
-# Prometheus exposition
-# ---------------------------------------------------------------------------
-def test_prometheus_text_shape():
-    m = Metrics(n_slots=4)
-    bus = EventBus([m])
-    bus.emit("start", 0.0)
-    bus.emit("admit", 0.1, tenant="t0", wait=0.1)
-    bus.emit("first_token", 0.2, tenant="t0", ttft=0.2)
-    bus.emit("token", 0.2, tenant="t0")
-    bus.emit("step", 0.3, n_active=1, path="segments-xla+packed")
-    bus.emit("done", 0.4, tenant="t0", latency=0.4)
-    bus.emit("stop", 0.5)
-    slo = SLOCounters(ttft_target_s=0.1)
-    slo.consume(_ev("first_token", tenant="t0", ttft=0.2))
-    text = prometheus_text(m, slo)
-    assert 'repro_serve_requests_total{tenant="t0"} 1' in text
-    assert 'repro_serve_tokens_total{tenant="t0"} 1' in text
-    assert ('repro_serve_decode_path_steps_total'
-            '{path="segments-xla+packed"} 1') in text
-    assert 'le="+Inf"}' in text                       # histogram terminal
-    assert 'repro_serve_ttft_seconds_count{tenant="t0"} 1' in text
-    assert 'repro_serve_ttft_violations_total{tenant="t0"} 1' in text
-    assert text.endswith("\n")
 
 
 # ---------------------------------------------------------------------------
