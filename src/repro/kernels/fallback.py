@@ -7,8 +7,8 @@ mathematically identical formulations with opposite scaling:
 * :func:`dense_correction` — scatter the packed delta to a dense
   ``[h_in, h_out]`` matrix, then one dense matmul. The scatter cost is
   paid once regardless of T, so it wins for prefill-sized token counts.
-* :func:`gather_correction` — never materialize the dense delta: gather
-  each kept element's activation by its (flattened) index and contract
+* :func:`gather_correction` — never materialize the dense delta: pick
+  each kept element's activation by its in-group index and contract
   against the dequantized values directly
   (``y[t,o] = sum_{g,k} x[t, g*h_g + idx[g,k,o]] * val[g,k,o]``).
   Work is ``T * nnz`` instead of ``nnz`` scatter + ``T * h_in * h_out``
@@ -17,6 +17,17 @@ mathematically identical formulations with opposite scaling:
 
 :func:`correction` picks between them by token count; the crossover is
 the autotuned ``gather_max_t`` (kernels/autotune.py).
+
+How a kept element's activation is picked depends on the group size.
+A DeltaDQ index is local to its dropout group, ``idx[g,k,o] < h_g``, so
+the activation is one of the h_g values ``x[t, g*h_g : (g+1)*h_g]``.
+For ``h_g <= SELECT_MAX_HG`` the pick is a select tree over the index's
+bits on those h_g columns (static slices): vector-unit work, where the
+TPU's elementwise gather costs ~14 cycles an element. Above it the pick
+is a gather by the flat ``h_in`` index: the select's work and compile
+time grow with h_g, the gather's do not. A select moves its operand's
+bits (-0.0 and NaN included), so both picks hand the same
+multiply-reduce the same values.
 
 Mixed-tenant decode adds two more:
 
@@ -59,11 +70,18 @@ def _note(site: str, **attrs) -> None:
     note_path(site, **attrs)
 
 
-def _flat_gather_idx(d: PackedDelta, idx: jnp.ndarray) -> jnp.ndarray:
-    """Local in-group indices [..., G, K, O] -> flat h_in indices."""
-    G = d.n_groups
-    base = (jnp.arange(G, dtype=jnp.int32) * d.h_g)[:, None, None]
-    return idx.astype(jnp.int32) + base
+# Largest dropout group whose kept activations are picked by an in-group
+# select; larger groups take the flat-index gather (module docstring).
+# On a v5e (N 8, h_in 5120, h_out 17920) the select took 4.8 / 2.8 / 6.1 ms
+# at h_g 16 / 64 / 128 against the gather's ~866 ms at each; compiling it
+# for a v5e (on a CPU host) took 3.0 s at 64, 9.5 s at 128 and 88 s at
+# 256, so it stops at 128.
+SELECT_MAX_HG = 128
+
+
+def _pick(h_g: int) -> str:
+    """Which way :func:`_rows_core` picks activations at group size h_g."""
+    return "select" if h_g <= SELECT_MAX_HG else "gather"
 
 
 def dense_correction(x2: jnp.ndarray, d: PackedDelta) -> jnp.ndarray:
@@ -85,10 +103,9 @@ def gather_correction(x2: jnp.ndarray, d: PackedDelta) -> jnp.ndarray:
     vals = decode_values(d)                          # [G, K, O] f32
     G, K, O = vals.shape
     T = x2.shape[0]
-    gidx = _flat_gather_idx(d, d.idx).reshape(1, G * K * O)
     # the per-row paths' contraction, every row on this one delta: one
-    # gather + reduce shape for shared and per-row deltas, one set of bits
-    return _rows_core(x2, jnp.broadcast_to(gidx, (T, G * K * O)),
+    # pick + reduce shape for shared and per-row deltas, one set of bits
+    return _rows_core(x2, jnp.broadcast_to(d.idx, (T, G, K, O)),
                       jnp.broadcast_to(vals.reshape(1, G * K, O),
                                        (T, G * K, O)))
 
@@ -97,7 +114,7 @@ def correction(x2: jnp.ndarray, d: PackedDelta, *,
                gather_max_t: int = 64) -> jnp.ndarray:
     """Formulation chooser: gather for decode-sized T, dense otherwise."""
     if x2.shape[0] <= gather_max_t:
-        _note("correction", formulation="xla-gather", codec=d.codec,
+        _note("correction", formulation=f"xla-{_pick(d.h_g)}", codec=d.codec,
               T=int(x2.shape[0]), gather_max_t=int(gather_max_t))
         return gather_correction(x2, d)
     _note("correction", formulation="xla-dense", codec=d.codec,
@@ -129,19 +146,56 @@ def correction_nd(x: jnp.ndarray, d: PackedDelta, *,
     return y.reshape(*lead, d.h_out)
 
 
-def _rows_core(x_rows: jnp.ndarray, gidx: jnp.ndarray,
+def _select_in_group(x3: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """x3 [N, G, h_g], idx [N, G, K, O] in-group indices -> [N, G, K, O]
+    with ``out[n,g,k,o] = x3[n, g, idx[n,g,k,o]]``.
+
+    A select tree over idx's bits: level b pairs the surviving columns
+    that differ in bit b, so h_g columns take h_g - 1 selects and
+    log2(h_g) bit tests per element. Columns are static slices
+    (``lax.index_in_dim`` lowers to a slice; ``jnp.take`` would lower to
+    a gather), padded to a power of two with columns no index reaches.
+    """
+    h_g = x3.shape[2]
+    idx = idx.astype(jnp.int32)
+    cols = [jax.lax.index_in_dim(x3, j, axis=2, keepdims=False)[..., None, None]
+            for j in range(h_g)]
+    cols += cols[-1:] * ((1 << (h_g - 1).bit_length()) - h_g)
+    bit = 1
+    while len(cols) > 1:
+        hi = (idx & bit) != 0
+        cols = [jnp.where(hi, c1, c0) for c0, c1 in zip(cols[0::2], cols[1::2])]
+        bit <<= 1
+    return jnp.broadcast_to(cols[0], idx.shape)
+
+
+def _rows_core(x_rows: jnp.ndarray, idx: jnp.ndarray,
                vals: jnp.ndarray) -> jnp.ndarray:
-    """Shared per-row contraction: x_rows [N, h_in], gidx [N, G*K*O] flat
-    h_in indices, vals [N, G*K, O] -> [N, O] f32.
+    """Shared per-row contraction: x_rows [N, h_in], idx [N, G, K, O]
+    in-group indices, vals [N, G*K, O] -> [N, O] f32.
 
     Every per-row path (row-gathered stack, segment dispatch) funnels
-    through this one function so the gather + reduce shapes — and
-    therefore the bits — are identical across dispatch modes.
+    through this one function so the pick + reduce shapes — and
+    therefore the bits — are identical across dispatch modes. The pick
+    is :func:`_select_in_group` up to ``SELECT_MAX_HG``, else a gather by
+    flat ``h_in`` index; either copies the same activations.
     """
-    N = x_rows.shape[0]
-    GK, O = vals.shape[1], vals.shape[2]
-    sel = jnp.take_along_axis(x_rows.astype(jnp.float32), gidx, axis=1)
-    sel = sel.reshape(N, GK, O)
+    N, h_in = x_rows.shape
+    _, G, K, O = idx.shape
+    h_g = h_in // G
+    x = x_rows.astype(jnp.float32)
+    if _pick(h_g) == "select":
+        # one pick + reduce whatever built the operands: XLA:CPU fuses the
+        # selects into a reduce it vectorizes with reassociation, and
+        # without the barrier that order followed the callers' structure
+        # (constants, broadcasts, row gathers), not just the shapes
+        x, idx, vals = jax.lax.optimization_barrier((x, idx, vals))
+        sel = _select_in_group(x.reshape(N, G, h_g), idx).reshape(
+            N, G * K, O)
+    else:
+        base = (jnp.arange(G, dtype=jnp.int32) * h_g)[:, None, None]
+        gidx = (idx.astype(jnp.int32) + base).reshape(N, G * K * O)
+        sel = jnp.take_along_axis(x, gidx, axis=1).reshape(N, G * K, O)
     return (sel * vals).sum(axis=1)
 
 
@@ -150,7 +204,7 @@ def gather_correction_rows(x: jnp.ndarray, d: PackedDelta,
                            ) -> jnp.ndarray:
     """Per-row deltas: x [B, ..., h_in], d row-stacked [B] -> [B, ..., h_out].
 
-    Peak extra memory is ``B * nnz`` floats (the gathered activations),
+    Peak extra memory is ``B * nnz`` floats (the picked activations),
     not ``B * h_in * h_out`` — rows sharing a tenant no longer multiply a
     dense reconstruction.
 
@@ -165,17 +219,16 @@ def gather_correction_rows(x: jnp.ndarray, d: PackedDelta,
     B = x.shape[0]
     vals = decode_values(d) if values is None else values   # [B, G, K, O]
     _, G, K, O = vals.shape
-    gidx = _flat_gather_idx(d, d.idx)                # [B, G, K, O]
     x2 = x.astype(jnp.float32).reshape(B, -1, d.h_in)
     T = x2.shape[1]
     # flatten (row, token) so the reduce shape matches gather_correction's
     # [rows, G*K, O] exactly — same bits as the shared-tenant path
     x_rows = x2.reshape(B * T, d.h_in)
-    gidx_rows = jnp.broadcast_to(
-        gidx.reshape(B, 1, G * K * O), (B, T, G * K * O)).reshape(B * T, -1)
+    idx_rows = jnp.broadcast_to(
+        d.idx[:, None], (B, T, G, K, O)).reshape(B * T, G, K, O)
     vals_rows = jnp.broadcast_to(
         vals.reshape(B, 1, G * K, O), (B, T, G * K, O)).reshape(B * T, G * K, O)
-    y = _rows_core(x_rows, gidx_rows, vals_rows)
+    y = _rows_core(x_rows, idx_rows, vals_rows)
     return y.reshape(*x.shape[:-1], d.h_out)
 
 
@@ -191,7 +244,7 @@ def segment_correction(x2: jnp.ndarray, d: PackedDelta,
     segment's half-open row range (S is a static shape — padding
     segments are empty). The packed (still-compressed) bytes are routed
     to rows through the segment map and contracted by the same
-    :func:`_rows_core` the per-row path uses — identical gather/reduce
+    :func:`_rows_core` the per-row path uses — identical pick/reduce
     shapes, identical bits.
 
     ``values``/``res_map`` (optional) select the pre-decoded residency
@@ -213,7 +266,8 @@ def segment_correction(x2: jnp.ndarray, d: PackedDelta,
     removes the unpack from the step altogether.
     """
     T = x2.shape[0]
-    _note("segment_correction", formulation="segments-xla", codec=d.codec,
+    form = "segments-xla-select" if _pick(d.h_g) == "select" else "segments-xla"
+    _note("segment_correction", formulation=form, codec=d.codec,
           residency="values" if values is not None else "packed", T=int(T))
     # map each (sorted) row to its segment: count of segment ends <= row
     rows_iota = jnp.arange(T, dtype=jnp.int32)

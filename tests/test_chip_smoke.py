@@ -52,7 +52,7 @@ def test_serving_and_correctness_phases(smoke_model):
     cfg, base, tenants, stream = smoke_model
     eng, reqs = chip_smoke.serving_phase(cfg, base, tenants, stream)
     assert set(eng.metrics.report()["decode_paths"]) == \
-        {"segments-xla+packed"}
+        {"segments-xla-select+packed"}
     ref, ident = chip_smoke.identity_phase(cfg, base, tenants, stream, reqs)
     # the CPU runs both engines' arithmetic identically: exact identity
     assert ident == {"exact": len(reqs), "diverged": []}

@@ -14,6 +14,8 @@ Three layers are checked against the dense-reconstruct oracle:
 The slow-marked sweep covers the full supported envelope
 (h_g x keep x k_bits); the fast subset runs per-PR.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -114,6 +116,86 @@ def test_gather_rows_no_dense_materialization_parity():
     got = ops.delta_spmm_slots(x, gat, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# How the fallback picks kept activations: in-group select or flat gather
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("h_g", [8, 16, 32, 64, 128])
+def test_select_pick_bit_equal_to_flat_index_oracle(h_g):
+    """The select copies x[n, g*h_g + idx] exactly: every bit, -0.0,
+    +-inf and NaN included, as a gather by flat index does."""
+    assert h_g <= fallback.SELECT_MAX_HG
+    N, G, K, O = 3, 5, max(h_g // 8, 2), 128
+    rng = np.random.default_rng(h_g)
+    x = rng.standard_normal((N, G * h_g)).astype(np.float32)
+    specials = np.array([-0.0, np.inf, -np.inf, np.nan], np.float32)
+    x.flat[rng.choice(x.size, 4 * len(specials), replace=False)] = \
+        np.repeat(specials, 4)
+    idx = rng.integers(0, h_g, (N, G, K, O)).astype(np.uint8)
+    idx[0, 0, 0, :h_g] = np.arange(h_g)        # every column picked once
+    flat = idx.astype(np.int64) + (np.arange(G) * h_g)[:, None, None]
+    want = np.take_along_axis(x, flat.reshape(N, -1), axis=1)
+    got = np.asarray(jax.jit(fallback._select_in_group)(
+        jnp.asarray(x).reshape(N, G, h_g), jnp.asarray(idx)))
+    np.testing.assert_array_equal(got.reshape(N, -1).view(np.uint32),
+                                  want.view(np.uint32))
+
+
+def _attributed_forms(h_g):
+    """Formulations the correction and the segment dispatch report at h_g."""
+    from repro.serve.trace import attribution
+    p = _pack(256, 96, h_g, 8, 4)
+    stk = stack_tenant_deltas([{"w": p}, {"w": p}])["w"]
+    seg = _segments([1, 0, 1])
+    with attribution() as notes:
+        fallback.correction(jnp.ones((3, 256)), p, gather_max_t=64)
+        fallback.segment_correction(jnp.ones((3, 256)), stk, seg.seg_rows,
+                                    seg.seg_offsets)
+    return {n["site"]: n["formulation"] for n in notes}
+
+
+@pytest.mark.parametrize("h_g,want", [
+    (16, {"correction": "xla-select",
+          "segment_correction": "segments-xla-select"}),
+    (2 * fallback.SELECT_MAX_HG, {"correction": "xla-gather",
+                                  "segment_correction": "segments-xla"}),
+])
+def test_pick_attribution_by_group_size(h_g, want):
+    """Up to SELECT_MAX_HG the attribution reports the select; above it,
+    the flat gather."""
+    assert _attributed_forms(h_g) == want
+
+
+def _activation_gathers(stablehlo: str, n_elems: int) -> list:
+    """StableHLO gathers whose operand is an f32 block of n_elems."""
+    found = []
+    for line in stablehlo.splitlines():
+        if '"stablehlo.gather"' not in line:
+            continue
+        operand = re.search(r": \(tensor<([0-9x]+)xf32>", line)
+        if operand and np.prod([int(d) for d in
+                                operand.group(1).split("x")]) == n_elems:
+            found.append(line.strip())
+    return found
+
+
+@pytest.mark.parametrize("h_g", [16, 2 * fallback.SELECT_MAX_HG])
+def test_segment_correction_lowers_no_activation_gather(h_g):
+    """At h_g 16 no gather reads the activation block: the pick is made
+    of static slices and selects. The gather path above SELECT_MAX_HG is
+    the control that the probe finds such a gather."""
+    T, h_in = 4, 256
+    stk = stack_tenant_deltas([{"w": _pack(h_in, 96, h_g, 8, 4, seed=s)}
+                               for s in range(3)])["w"]
+    seg = _segments([2, 0, 1, 2])
+    text = jax.jit(fallback.segment_correction).lower(
+        jnp.zeros((T, h_in)), stk, seg.seg_rows, seg.seg_offsets).as_text()
+    found = _activation_gathers(text, T * h_in)
+    if h_g <= fallback.SELECT_MAX_HG:
+        assert found == []
+    else:
+        assert found
 
 
 # ---------------------------------------------------------------------------

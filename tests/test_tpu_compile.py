@@ -7,12 +7,15 @@ about results or speed.
 
 Widths are Llama-3.2-1B's (h_in 2048 -> h_out 512 / 2048 / 8192: the
 k/v, q/o and MLP projections) at the lane-aligned group sizes the
-kernels accept, for an 8-slot decode batch of 4-bit codes.
+kernels accept, for an 8-slot decode batch of 4-bit codes; the served
+XLA correction also at the group sizes its in-group select takes.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and under several pytest
 workers every worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -107,9 +110,34 @@ def test_delta_spmm_segments_compiles(one_chip, h_g, h_out):
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.mark.parametrize("h_g,h_out", CASES)
+def _gathers_reading(hlo: str, n_elems: int) -> list:
+    """Compiled-HLO gathers whose operand is an f32 array of n_elems
+    (instruction names are unique in a module, so a name gives a type)."""
+    types = dict(re.findall(r"%(\S+) = (\w+\[[0-9,]*\])", hlo))
+    found = []
+    for name, operand in re.findall(r"%(\S+) = \S+ gather\(%([^,\s]+)", hlo):
+        dtype, dims = types.get(operand, "?[]").rstrip("]").split("[")
+        size = 1
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        if dtype == "f32" and size == n_elems:
+            found.append(name)
+    return found
+
+
+# group sizes the in-group select serves (the fleet's h_g 16 among them,
+# and 128) and one above SELECT_MAX_HG, which keeps the flat gather
+SERVED_CASES = [(h_g, h_out) for h_g in (16, 64) + H_GS for h_out in H_OUTS]
+
+
+@pytest.mark.parametrize("h_g,h_out", SERVED_CASES)
 def test_served_segment_correction_compiles(one_chip, h_g, h_out):
-    """The XLA formulation the engine serves with today."""
+    """The XLA formulation the engine serves with today. Up to
+    SELECT_MAX_HG no gather reads the [T, h_in] activations; above it
+    the flat gather does, which shows the probe finds one."""
     c = _compile(fallback.segment_correction,
                  *_segment_args(h_g, h_out, one_chip))
-    assert "tpu_custom_call" not in c.as_text()
+    text = c.as_text()
+    assert "tpu_custom_call" not in text
+    fed = _gathers_reading(text, T * H_IN)
+    assert (fed == []) == (h_g <= fallback.SELECT_MAX_HG), fed
